@@ -190,13 +190,7 @@ def _decode_rows() -> list[str]:
     rows.append(f"ingest,decode,none_arena,records_per_s,{plain_rps:.1f}")
     rows.append(f"ingest,decode,none_arena,bytes_copied_per_record,"
                 f"{plain_bpr:.1f}")
-    codecs = ["gzip", "lz4"]
-    try:
-        import zstandard  # noqa: F401
-        codecs.append("zstd")
-    except ImportError:
-        pass
-    for codec in codecs:
+    for codec in ("gzip", "lz4", "zstd"):
         # gzip gets a larger corpus: the process-readahead fork/ring
         # setup (~5-8 ms) must amortize the way it does on real
         # (100 MB+) shards, not dominate a 1 MB toy file. LZ4/zstd keep

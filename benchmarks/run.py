@@ -5,7 +5,7 @@ Sections (CSV on stdout, ``section,...`` prefixed rows):
                workload, with speedups (benchmarks/table1.py);
   * pipeline — end-to-end WARC→tokens ingestion + the paper's
                Common-Crawl hours-saved projections;
-  * kernels  — Pallas kernel micro-benches (interpret mode);
+  * kernels  — Pallas kernel micro-benches (interpret mode on the CPU);
   * parallel — multi-worker shard fan-out scaling + batched-vs-looped
                kernel dispatch (benchmarks/parallel_bench.py);
   * index    — CDX build throughput, random-access vs sequential
@@ -93,6 +93,9 @@ def main(argv: list[str] | None = None) -> None:
                             "columnar,kernels",
                     help="comma-separated subset of sections to run")
     args = ap.parse_args(argv)
+    from repro.kernels import enable_compile_cache
+
+    enable_compile_cache()
     sections = [s.strip() for s in args.sections.split(",") if s.strip()]
     known = {"table1", "pipeline", "kernels", "parallel", "index", "serve",
              "ingest", "columnar"}

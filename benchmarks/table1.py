@@ -71,12 +71,7 @@ def run(pages: int = _PAGES, quiet: bool = False) -> list[Row]:
     rows: list[Row] = []
     gzip_fast_parse: float | None = None
 
-    try:
-        import zstandard  # noqa: F401
-        codecs = ("none", "gzip", "lz4", "zstd")
-    except ImportError:  # optional codec; container images vary
-        codecs = ("none", "gzip", "lz4")
-    for comp in codecs:
+    for comp in ("none", "gzip", "lz4", "zstd"):
         data = generate_warc(spec, comp)
         for workload, kw in _WORKLOADS.items():
             fast = _best_of(_fast(data, **kw))

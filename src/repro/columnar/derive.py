@@ -128,7 +128,7 @@ def derive(paths, out_path: str, *, workers: int = 0,
            sig_hashes: int = SIG_HASHES,
            max_rows: int = RG_MAX_ROWS, max_bytes: int = RG_MAX_BYTES,
            readahead: bool | None = None, tolerant: bool = False,
-           supervise: bool = False, interpret: bool = True) -> ColumnStore:
+           supervise: bool = False) -> ColumnStore:
     """Derive columnar shards from a WARC corpus; returns the opened store.
 
     One parser sweep per source shard (``workers > 0`` fans out through
@@ -253,7 +253,7 @@ def derive(paths, out_path: str, *, workers: int = 0,
             if use_fused:
                 d, s = digest_signature_rowgroup(
                     mat, glens, bits=sig_bits, n=sig_ngram, k=sig_hashes,
-                    block=min(_BLOCK, spec.width), interpret=interpret)
+                    block=min(_BLOCK, spec.width))
             else:  # geometry outside the kernel: host two-pass per row
                 d = np.asarray([zlib.adler32(views[rec]) & 0xFFFFFFFF
                                 for rec in spec.rows], np.uint32)

@@ -55,8 +55,8 @@ def verify_digest(data: bytes | memoryview, header_value: str) -> bool:
     return False
 
 
-def verify_digests_bulk(datas, header_values, *, use_kernel: bool = True,
-                        interpret: bool = True) -> list[bool]:
+def verify_digests_bulk(datas, header_values, *, use_kernel: bool = True
+                        ) -> list[bool]:
     """Verify many ``algo:value`` digest headers at once.
 
     The batched path exists for the Adler-32 entries: instead of one
@@ -88,8 +88,7 @@ def verify_digests_bulk(datas, header_values, *, use_kernel: bool = True,
     if adler_idx:
         from repro.kernels.adler32 import adler32_batch
 
-        got = adler32_batch([datas[i] for i in adler_idx],
-                            interpret=interpret)
+        got = adler32_batch([datas[i] for i in adler_idx])
         for j, i in enumerate(adler_idx):
             results[i] = int(got[j]) == adler_expected[j]
     return results

@@ -36,6 +36,7 @@ import os
 import pickle
 import queue
 import select
+import signal
 import struct
 import sys
 import threading
@@ -43,10 +44,7 @@ import time
 import zlib
 from typing import BinaryIO, Iterator
 
-try:
-    import zstandard as _zstd
-except ImportError:  # pragma: no cover - zstandard ships in the image
-    _zstd = None
+import zstandard as _zstd
 
 try:
     from multiprocessing import shared_memory as _shm_mod
@@ -516,8 +514,6 @@ class ZstdStream:
     """
 
     def __init__(self, raw: BinaryIO) -> None:
-        if _zstd is None:  # pragma: no cover
-            raise RuntimeError("zstandard not available")
         self._reader = _zstd.ZstdDecompressor().stream_reader(
             raw, read_across_frames=True)
 
@@ -1330,6 +1326,12 @@ def _member_decode_child(src, shm_name: str, slot_bytes: int, slots: int,
     order, matching the synchronous path).
     """
     os.close(rfd)  # parent's read end: child must not hold it open
+    # fork copied the parent's signal handlers, and a parent that holds a
+    # TPU has its runtime's crash handlers on these: restore the defaults
+    # so the parent's terminate() never runs accelerator-runtime code here
+    for sig in (signal.SIGTERM, signal.SIGABRT, signal.SIGBUS,
+                signal.SIGFPE, signal.SIGILL, signal.SIGSEGV):
+        signal.signal(sig, signal.SIG_DFL)
     try:
         raw = open(src, "rb") if isinstance(src, str) else io.BytesIO(src)
         stream, _kind = open_member_stream_at(raw, start_offset)

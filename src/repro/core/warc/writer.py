@@ -14,10 +14,7 @@ import zlib
 from datetime import datetime, timezone
 from typing import BinaryIO
 
-try:
-    import zstandard as _zstd
-except ImportError:  # pragma: no cover
-    _zstd = None
+import zstandard as _zstd
 
 from . import lz4 as _lz4
 from .checksum import block_digest
@@ -66,8 +63,6 @@ class WarcWriter:
                  *, lz4_content_checksum: bool = False) -> None:
         if compression not in ("none", "gzip", "lz4", "zstd"):
             raise ValueError(f"unknown compression {compression!r}")
-        if compression == "zstd" and _zstd is None:  # pragma: no cover
-            raise RuntimeError("zstandard not available")
         self._sink = sink
         self.compression = compression
         self._lz4_chk = lz4_content_checksum

@@ -29,10 +29,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-try:
-    import zstandard as _zstd
-except ImportError:  # pragma: no cover - exercised on zstd-less installs
-    _zstd = None
+import zstandard as _zstd
 
 __all__ = ["ZstdFrameInfo", "frame_table", "walk_frames"]
 
@@ -122,9 +119,6 @@ def walk_frames(blob: bytes) -> list[ZstdFrameInfo]:
 
 def _measure(blob, frame: ZstdFrameInfo) -> int:
     """Decompressed size of one frame whose header omits it."""
-    if _zstd is None:  # pragma: no cover - needs a zstd-less install
-        raise RuntimeError(
-            "zstandard needed to size a frame without Frame_Content_Size")
     reader = _zstd.ZstdDecompressor().stream_reader(
         io.BytesIO(bytes(blob[frame.comp_off:frame.comp_off + frame.comp_len])))
     total = 0

@@ -357,10 +357,10 @@ _FUSED_BATCH_BYTES = 32 << 20  # …or payload bytes, whichever trips first:
 
 def _fused_supported(sig_bits: int, sig_ngram: int) -> bool:
     """Geometry the fused kernel path covers (else: host two-pass)."""
-    from repro.kernels.digest_sig.digest_sig import HPAD
+    from repro.kernels.digest_sig.digest_sig import MAX_NGRAM
 
     return (sig_bits & (sig_bits - 1) == 0
-            and 2 <= sig_ngram <= HPAD + 1)
+            and 2 <= sig_ngram <= MAX_NGRAM)
 
 
 def _index_shard(path: str, *, sig_bits: int = SIG_BITS,
@@ -569,8 +569,12 @@ def build_index(paths, *, workers: int = 0, sig_bits: int = SIG_BITS,
     host path; the two produce bit-identical columns. Default (None):
     fused for serial builds, host in worker processes — pool workers
     may fork before/without JAX and must not drag a fresh runtime up
-    per shard. Geometries the kernel does not cover (non-power-of-two
-    ``sig_bits``) silently use the host path.
+    per shard. On an accelerator, ``fused=True`` with ``workers > 0``
+    raises ``ValueError``: one process holds the chip, so the kernel
+    cannot be dispatched from pool workers (``derive`` keeps it in the
+    parent). Geometries the kernel does not cover (non-power-of-two
+    ``sig_bits``, n-grams past ``MAX_NGRAM``) silently use the host
+    path.
 
     The signature geometry (``sig_bits``/``sig_ngram``/``sig_hashes``)
     is a **per-index build parameter**: it is persisted in the CDX
@@ -609,6 +613,15 @@ def build_index(paths, *, workers: int = 0, sig_bits: int = SIG_BITS,
         raise ValueError("sig_ngram and sig_hashes must be >= 1")
     if fused is None:
         fused = workers == 0
+    if fused and workers > 0:
+        import jax
+
+        if jax.default_backend() != "cpu":
+            raise ValueError(
+                "fused=True with workers > 0 would dispatch the digest "
+                "kernel from pool workers, but one process holds the "
+                f"{jax.default_backend()} device: build with workers=0, "
+                "or fused=False, or derive() a columnar store")
     sweep = functools.partial(_index_shard, sig_bits=sig_bits,
                               sig_ngram=sig_ngram, sig_hashes=sig_hashes,
                               fused=fused, readahead=readahead,
@@ -712,7 +725,7 @@ class RandomAccessReader:
 
 
 def verify_index(index: CdxIndex, *, limit: int | None = None,
-                 use_kernel: bool = True, interpret: bool = True,
+                 use_kernel: bool = True,
                  check_signatures: bool = False) -> list[bool]:
     """Bulk-verify indexed adler32 digests against re-read record content.
 
@@ -768,15 +781,14 @@ def verify_index(index: CdxIndex, *, limit: int | None = None,
                 end += 1
             digests, sigs = digest_signature_batch(
                 datas[start:end], bits=index.sig_bits, n=index.sig_ngram,
-                k=index.sig_hashes, interpret=interpret)
+                k=index.sig_hashes)
             ok[start:end] = ((digests == expected[start:end])
                              & (sigs == index.signatures[start:end])
                              .all(axis=1))
             start = end
         return [bool(b) for b in ok]
     headers = [f"adler32:{int(d):08x}" for d in expected]
-    results = verify_digests_bulk(datas, headers, use_kernel=use_kernel,
-                                  interpret=interpret)
+    results = verify_digests_bulk(datas, headers, use_kernel=use_kernel)
     if check_signatures:
         for i, data in enumerate(datas):
             sig = signature_of(data, bits=index.sig_bits,

@@ -252,7 +252,7 @@ class QueryEngine:
                  store: "ColumnStore | None" = None,
                  batch_records: int = _DEFAULT_BATCH_RECORDS,
                  batch_bytes: int = _DEFAULT_BATCH_BYTES,
-                 use_kernel: bool = True, interpret: bool = True,
+                 use_kernel: bool = True,
                  scan_block: int = _DEFAULT_SCAN_BLOCK,
                  excerpt_bytes: int = 80) -> None:
         self.index = index
@@ -260,7 +260,6 @@ class QueryEngine:
         self.batch_bytes = max(1, batch_bytes)
         self.scan_block = scan_block
         self.use_kernel = use_kernel
-        self.interpret = interpret
         self.excerpt_bytes = excerpt_bytes
         self._readers: dict[int, RandomAccessReader] = {}
         self._store: "ColumnStore | None" = None
@@ -508,8 +507,7 @@ class QueryEngine:
                 # dense: one dispatch over the whole mmapped matrix
                 source, _, all_lens = store.rowgroup(g)
                 masks = find_pattern_mask_rowgroup(
-                    source, all_lens, plan.kernel_pattern,
-                    interpret=self.interpret, trim=False)
+                    source, all_lens, plan.kernel_pattern, trim=False)
                 mask_rows = store.rg_row[chunk].astype(np.int64)
                 mask_lens = all_lens
             else:
@@ -520,8 +518,7 @@ class QueryEngine:
                     (quantize_count(chunk.size), matrix.shape[1]), np.uint8)
                 source[:chunk.size] = matrix[sel]
                 masks = find_pattern_mask_rowgroup(
-                    source, lengths, plan.kernel_pattern,
-                    interpret=self.interpret, trim=False)
+                    source, lengths, plan.kernel_pattern, trim=False)
                 mask_rows = np.arange(chunk.size)
                 mask_lens = lengths
             self.stats["kernel_dispatches"] += 1
@@ -593,8 +590,7 @@ class QueryEngine:
             from repro.kernels.pattern_scan import find_pattern_mask_batch
 
             masks = find_pattern_mask_batch(bufs, plan.kernel_pattern,
-                                            block=self.scan_block,
-                                            interpret=self.interpret)
+                                            block=self.scan_block)
             lit_positions = [np.flatnonzero(m) for m in masks]
             self.stats["kernel_dispatches"] += dispatch_count(
                 [len(b) for b in bufs], self.scan_block)

@@ -54,10 +54,10 @@ class IndexQueryService:
     """Drain query requests in batches against one shared engine."""
 
     def __init__(self, index: CdxIndex, *, batch_size: int = 8,
-                 use_kernel: bool = True, interpret: bool = True,
+                 use_kernel: bool = True,
                  engine: QueryEngine | None = None) -> None:
         self.engine = engine if engine is not None else QueryEngine(
-            index, use_kernel=use_kernel, interpret=interpret)
+            index, use_kernel=use_kernel)
         self.batch_size = max(1, batch_size)
         self._queue: list[QueryRequest] = []
         self.stats = {"requests": 0, "batches": 0, "hits_returned": 0,

@@ -23,8 +23,15 @@ from __future__ import annotations
 
 import numpy as np
 
-__all__ = ["ROWGROUP_PAD", "SMALL_BLOCK", "as_u8", "bucket_width",
-           "dispatch_count", "payload_width", "quantize_count"]
+__all__ = ["LANES", "ROWGROUP_PAD", "SMALL_BLOCK", "SUBLANES", "as_u8",
+           "bucket_width", "dispatch_count", "grid_tiles", "payload_width",
+           "quantize_count"]
+
+# The TPU vector register is (SUBLANES, LANES) 32-bit words. A kernel
+# block's last dimension is a multiple of LANES and its row count a
+# multiple of SUBLANES, or either equals the whole array's extent.
+LANES = 128
+SUBLANES = 8
 
 # Width-bucket granularity for payloads below one full kernel block
 # (digest path: the 2048 Adler block is an overflow *bound*, not a width
@@ -80,6 +87,37 @@ def payload_width(size: int, block: int, small: int | None = SMALL_BLOCK
     if small and size <= block:
         return min(bucket_width(size, small), block)
     return bucket_width(size, block)
+
+
+def grid_tiles(nrows: int, width: int, *, unit: int, budget: int,
+               max_cols: int | None = None) -> tuple[int, int]:
+    """``(rows, cols)`` of one kernel grid step over a ``(nrows, width)``
+    byte matrix.
+
+    A step costs its rows rounded up to :data:`SUBLANES` (the vector
+    registers a short tile still fills) times ``cols``. ``cols`` is
+    ``width`` halved while the step would exceed ``budget`` at its fewest
+    rows (or exceed ``max_cols``), as long as the half stays a multiple
+    of ``unit``; ``rows`` is all of ``nrows`` when that fits, else the
+    largest multiple of SUBLANES that divides ``nrows`` and fits. A row
+    count that is not a SUBLANES multiple can only be tiled whole.
+    Counts from :func:`quantize_count` (``m·2^k``, m ∈ {1, 3}) above 12
+    are SUBLANES multiples, so the divisor exists.
+    """
+    def cost(rows: int, cols: int) -> int:
+        return -(-rows // SUBLANES) * SUBLANES * cols
+
+    min_rows = SUBLANES if nrows % SUBLANES == 0 else nrows
+    cols = width
+    while ((cost(min_rows, cols) > budget or (max_cols and cols > max_cols))
+           and cols % (2 * unit) == 0):
+        cols //= 2
+    if cost(nrows, cols) <= budget or min_rows == nrows:
+        return nrows, cols
+    rows = max(SUBLANES, budget // cols // SUBLANES * SUBLANES)
+    while nrows % rows:
+        rows -= SUBLANES
+    return rows, cols
 
 
 def dispatch_count(sizes, block: int) -> int:

@@ -4,7 +4,13 @@ One batched Pallas sweep produces both per-record CDX byte columns —
 the content digest and the query pre-filter signature — so index
 construction touches each payload byte once.
 """
-from .digest_sig import BLOCK, FNV_PRIME, HPAD, digest_sig_partials_batch
+from .digest_sig import (
+    BLOCK,
+    FNV_PRIME,
+    HPAD,
+    MAX_NGRAM,
+    digest_sig_partials_batch,
+)
 from .ops import digest_signature_batch, digest_signature_rowgroup
 from .ref import digest_signature_reference
 
@@ -12,6 +18,7 @@ __all__ = [
     "BLOCK",
     "FNV_PRIME",
     "HPAD",
+    "MAX_NGRAM",
     "digest_sig_partials_batch",
     "digest_signature_batch",
     "digest_signature_rowgroup",

@@ -31,7 +31,7 @@ from repro.kernels.bucketing import (
     quantize_count,
 )
 from repro.obs.kernels import record_dispatch
-from .digest_sig import BLOCK, HPAD, digest_sig_partials_batch
+from .digest_sig import BLOCK, HPAD, MAX_NGRAM, digest_sig_partials_batch
 
 __all__ = ["digest_signature_batch", "digest_signature_rowgroup"]
 
@@ -40,7 +40,7 @@ def _pad_rows(n: int) -> int:
     """Row-count bucket: half-step quantized (1, 2, 3, 4, 6, 8, 12, …),
     so repeated ragged batches reuse a bounded set of compiled shapes
     while row padding stays ≤ 1.5× (pad rows are all-zero; their outputs
-    are discarded). The kernel's row group adapts to any quantized count."""
+    are discarded). The kernel's row tiles divide any quantized count."""
     return quantize_count(n)
 
 
@@ -55,8 +55,8 @@ def _sig_geometry(bits: int | None, n: int | None, k: int | None
     if bits <= 0 or bits & (bits - 1) or bits % 64:
         raise ValueError(f"bits must be a power of two multiple of 64, "
                          f"got {bits}")
-    if not 1 < n <= HPAD + 1 or k < 1:
-        raise ValueError(f"need 2 <= n <= {HPAD + 1} and k >= 1")
+    if not 1 < n <= MAX_NGRAM or k < 1:
+        raise ValueError(f"need 2 <= n <= {MAX_NGRAM} and k >= 1")
     return bits, n, k
 
 
@@ -89,7 +89,7 @@ def _host_fold(s, t, h, lengths: np.ndarray, *, width: int, bits: int,
 
 def digest_signature_batch(payloads, *, bits: int | None = None,
                            n: int | None = None, k: int | None = None,
-                           block: int = BLOCK, interpret: bool = True
+                           block: int = BLOCK
                            ) -> tuple[np.ndarray, np.ndarray]:
     """Adler-32 digests **and** n-gram signatures of a ragged batch,
     one fused kernel sweep per width bucket.
@@ -123,7 +123,7 @@ def digest_signature_batch(payloads, *, bits: int | None = None,
                         rows=len(idxs), padded_rows=padded.shape[0],
                         useful_bytes=int(lengths.sum()))
         s, t, h = digest_sig_partials_batch(jnp.asarray(padded), n=n,
-                                            block=kblock, interpret=interpret)
+                                            block=kblock)
         digests[idxs], sigs[idxs] = _host_fold(
             s, t, h, lengths, width=width, bits=bits, n=n, k=k, block=kblock)
     return digests, sigs
@@ -131,7 +131,7 @@ def digest_signature_batch(payloads, *, bits: int | None = None,
 
 def digest_signature_rowgroup(matrix, lengths, *, bits: int | None = None,
                               n: int | None = None, k: int | None = None,
-                              block: int = BLOCK, interpret: bool = True
+                              block: int = BLOCK
                               ) -> tuple[np.ndarray, np.ndarray]:
     """Fused digests + signatures over an **already-packed row-group**.
 
@@ -162,7 +162,6 @@ def digest_signature_rowgroup(matrix, lengths, *, bits: int | None = None,
         raise ValueError("length exceeds row-group width")
     record_dispatch("digest_signature_rowgroup", width=width, rows=live,
                     padded_rows=nrows, useful_bytes=int(lengths.sum()))
-    s, t, h = digest_sig_partials_batch(jnp.asarray(mat), n=n, block=block,
-                                        interpret=interpret)
+    s, t, h = digest_sig_partials_batch(jnp.asarray(mat), n=n, block=block)
     return _host_fold(s, t, h, lengths, width=width, bits=bits, n=n, k=k,
                       block=block)

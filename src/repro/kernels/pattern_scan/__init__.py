@@ -4,11 +4,9 @@ from .ops import (
     find_pattern_mask_batch,
     find_pattern_mask_rowgroup,
     find_pattern_masks_multi,
-    find_pattern_masks_multi_rowgroup,
     find_pattern_positions,
 )
 
 __all__ = ["find_pattern_mask", "find_pattern_mask_batch",
            "find_pattern_mask_rowgroup", "find_pattern_masks_multi",
-           "find_pattern_masks_multi_rowgroup", "find_pattern_positions",
-           "count_matches"]
+           "find_pattern_positions", "count_matches"]

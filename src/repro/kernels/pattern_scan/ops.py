@@ -2,12 +2,12 @@
 
 ``find_pattern_mask`` scans one buffer; ``find_pattern_mask_batch`` packs
 a ragged batch of payloads into padded byte matrices and issues one
-``(B, nblocks)``-gridded dispatch per power-of-two **width bucket**
-(parity with ``adler32_batch``): a uniform batch costs a single dispatch,
-repeated ragged batches reuse a handful of compiled shapes instead of
-recompiling per max-length, and one giant outlier cannot inflate every
-row to its width. Both wrappers build the explicit halo input the
-blocked kernel needs (see :mod:`.pattern_scan`).
+gridded dispatch per half-step **width bucket** (parity with
+``adler32_batch``): a uniform batch costs a single dispatch, repeated
+ragged batches reuse a handful of compiled shapes instead of recompiling
+per max-length, and one giant outlier cannot inflate every row to its
+width. Every matrix is packed in the row-group layout (payload
+left-justified, ``ROWGROUP_PAD`` zero tail) the kernel reads.
 """
 from __future__ import annotations
 
@@ -15,6 +15,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.kernels.bucketing import (
+    LANES,
     ROWGROUP_PAD,
     as_u8 as _as_u8,
     bucket_width,
@@ -24,44 +25,43 @@ from repro.obs.kernels import record_dispatch
 from .pattern_scan import (
     DEFAULT_BLOCK,
     MAX_PATTERN,
-    pattern_scan_batch,
     pattern_scan_batch_multi,
     pattern_scan_rowgroup,
-    pattern_scan_rowgroup_multi,
 )
 
 __all__ = ["find_pattern_mask", "find_pattern_mask_batch",
            "find_pattern_mask_rowgroup", "find_pattern_masks_multi",
-           "find_pattern_masks_multi_rowgroup", "find_pattern_positions",
-           "count_matches"]
+           "find_pattern_positions", "count_matches"]
 
 
 def _check_pattern(pattern) -> tuple[np.ndarray, int]:
+    """The kernel's pattern vector: ``(MAX_PATTERN,)`` int32, the bytes
+    first and -1 after them, plus the true length."""
     pat = _as_u8(pattern)
     if not 0 < pat.size <= MAX_PATTERN:
         raise ValueError(f"pattern length must be in [1, {MAX_PATTERN}]")
-    # zero-pad never false-positives: pattern bytes are non-zero in WARC use;
-    # all-zero patterns are rejected to keep that invariant
+    # the zero tail never false-positives: pattern bytes are non-zero in
+    # WARC use; all-zero patterns are rejected to keep that invariant
     if not pat.any():
         raise ValueError("all-zero patterns are not supported")
-    pad_vec = np.zeros(MAX_PATTERN, dtype=np.uint8)
-    pad_vec[:pat.size] = pat
-    return pad_vec, int(pat.size)
+    vec = np.full(MAX_PATTERN, -1, dtype=np.int32)
+    vec[:pat.size] = pat
+    return vec, int(pat.size)
 
 
-def _pack(bufs: list[np.ndarray], block: int, width: int
-          ) -> tuple[np.ndarray, np.ndarray]:
-    """Stack ragged buffers into (B, width) plus each tile's right-edge halo."""
-    nblocks = width // block
-    # W + MAX_PATTERN scratch so every halo gather is in-bounds (zeros there)
-    ext = np.zeros((len(bufs), width + MAX_PATTERN), dtype=np.uint8)
+def _check_block(block: int) -> None:
+    if block <= 0 or block % LANES:
+        raise ValueError(f"block must be a positive multiple of {LANES}, "
+                         f"got {block}")
+
+
+def _pack(bufs: list[np.ndarray], width: int) -> np.ndarray:
+    """Stack ragged buffers into the ``(B, width + ROWGROUP_PAD)``
+    row-group layout."""
+    mat = np.zeros((len(bufs), width + ROWGROUP_PAD), dtype=np.uint8)
     for i, buf in enumerate(bufs):
-        ext[i, :buf.size] = buf
-    # halo for tile j = bytes [ (j+1)·block , (j+1)·block + MAX_PATTERN )
-    gather = ((np.arange(nblocks)[:, None] + 1) * block
-              + np.arange(MAX_PATTERN)[None, :])        # (nblocks, MP)
-    halos = ext[:, gather.reshape(-1)]                  # (B, nblocks·MP)
-    return ext[:, :width], halos
+        mat[i, :buf.size] = buf
+    return mat
 
 
 def _pad_rows(n: int) -> int:
@@ -82,17 +82,18 @@ def _trim(mask_row: np.ndarray, n: int, plen: int) -> np.ndarray:
     return out
 
 
-def find_pattern_mask_batch(bufs, pattern, *, block: int = DEFAULT_BLOCK,
-                            interpret: bool = True) -> list[np.ndarray]:
+def find_pattern_mask_batch(bufs, pattern, *, block: int = DEFAULT_BLOCK
+                            ) -> list[np.ndarray]:
     """uint8 match masks for a ragged batch — few kernel dispatches.
 
     Returns one mask per input, each the same length as its buffer.
-    Inputs are grouped into power-of-two width buckets — one
-    ``(B, nblocks)``-gridded call per bucket — so a uniform batch is a
-    single dispatch and ragged query batches hit a bounded set of
-    compiled shapes (padding waste ≤ 2× per row).
+    Inputs are grouped into half-step width buckets of ``block``-byte
+    units — one gridded call per bucket — so a uniform batch is a single
+    dispatch and ragged query batches hit a bounded set of compiled
+    shapes (padding waste ≤ 1.5× per row).
     """
     pat_vec, plen = _check_pattern(pattern)
+    _check_block(block)
     arrs = [_as_u8(b) for b in bufs]
     if not arrs:
         return []
@@ -104,26 +105,24 @@ def find_pattern_mask_batch(bufs, pattern, *, block: int = DEFAULT_BLOCK,
     for width, idxs in buckets.items():
         rows = [arrs[i] for i in idxs]
         rows += [empty] * (_pad_rows(len(rows)) - len(rows))
-        padded, halos = _pack(rows, block, width)
         record_dispatch("find_pattern_mask_batch", width=width,
                         rows=len(idxs), padded_rows=len(rows),
                         useful_bytes=sum(arrs[i].size for i in idxs))
-        masks = pattern_scan_batch(jnp.asarray(padded), jnp.asarray(halos),
-                                   jnp.asarray(pat_vec), pat_len=plen,
-                                   block=block, interpret=interpret)
+        masks = pattern_scan_rowgroup(jnp.asarray(_pack(rows, width)),
+                                      jnp.asarray(pat_vec), pat_len=plen)
         masks = np.asarray(masks)
         for row, i in enumerate(idxs):
             out[i] = _trim(masks[row], arrs[i].size, plen)
     return out
 
 
-def find_pattern_masks_multi(bufs, patterns, *, block: int = DEFAULT_BLOCK,
-                             interpret: bool = True) -> list[np.ndarray]:
+def find_pattern_masks_multi(bufs, patterns, *, block: int = DEFAULT_BLOCK
+                             ) -> list[np.ndarray]:
     """Match masks for a ragged batch where **each row has its own
     pattern** — the cross-request batching entry point.
 
     ``patterns[i]`` scans ``bufs[i]``; rows from different queries that
-    land in the same power-of-two width bucket share one
+    land in the same width bucket share one
     ``pattern_scan_batch_multi`` dispatch (the unroll bound is the
     bucket's longest pattern). Same bucketing/trim semantics as
     :func:`find_pattern_mask_batch`, so for equal patterns the two are
@@ -131,6 +130,7 @@ def find_pattern_masks_multi(bufs, patterns, *, block: int = DEFAULT_BLOCK,
     """
     if len(bufs) != len(patterns):
         raise ValueError("bufs and patterns must pair up")
+    _check_block(block)
     arrs = [_as_u8(b) for b in bufs]
     pats: list[np.ndarray] = []
     plens: list[int] = []
@@ -145,40 +145,34 @@ def find_pattern_masks_multi(bufs, patterns, *, block: int = DEFAULT_BLOCK,
     for i, arr in enumerate(arrs):
         buckets.setdefault(bucket_width(arr.size, block), []).append(i)
     empty = np.empty(0, np.uint8)
-    pad_pat = np.zeros(MAX_PATTERN, np.uint8)
+    pad_pat = np.full(MAX_PATTERN, -1, np.int32)
     pad_pat[0] = 1  # inert: never matches an all-zero pad row
     for width, idxs in buckets.items():
         rows = [arrs[i] for i in idxs]
         n_pad = _pad_rows(len(rows)) - len(rows)
         rows += [empty] * n_pad
-        padded, halos = _pack(rows, block, width)
         pat_mat = np.stack([pats[i] for i in idxs] + [pad_pat] * n_pad)
-        lens = np.asarray([[plens[i]] for i in idxs] + [[1]] * n_pad,
-                          np.int32)
         record_dispatch("find_pattern_masks_multi", width=width,
                         rows=len(idxs), padded_rows=len(rows),
                         useful_bytes=sum(arrs[i].size for i in idxs))
         masks = pattern_scan_batch_multi(
-            jnp.asarray(padded), jnp.asarray(halos), jnp.asarray(pat_mat),
-            jnp.asarray(lens), max_len=max(plens[i] for i in idxs),
-            block=block, interpret=interpret)
+            jnp.asarray(_pack(rows, width)), jnp.asarray(pat_mat),
+            max_len=max(plens[i] for i in idxs))
         masks = np.asarray(masks)
         for row, i in enumerate(idxs):
             out[i] = _trim(masks[row], arrs[i].size, plens[i])
     return out
 
 
-def _trim_rows(masks: np.ndarray, lengths: np.ndarray, plens) -> np.ndarray:
+def _trim_rows(masks: np.ndarray, lengths: np.ndarray, plen: int
+               ) -> np.ndarray:
     """Vectorized :func:`_trim` over row-group masks: zero every position
     whose match window would read past its row's true length."""
-    width = masks.shape[1]
-    last = np.maximum(lengths[:, None] - np.asarray(plens).reshape(-1, 1) + 1,
-                      0)
-    return np.where(np.arange(width)[None, :] < last, masks, 0)
+    last = np.maximum(lengths[:, None] - plen + 1, 0)
+    return np.where(np.arange(masks.shape[1])[None, :] < last, masks, 0)
 
 
 def find_pattern_mask_rowgroup(matrix, lengths, pattern, *,
-                               interpret: bool = True,
                                trim: bool = True) -> np.ndarray:
     """Match masks over an **already-packed row-group** — one dispatch.
 
@@ -186,7 +180,7 @@ def find_pattern_mask_rowgroup(matrix, lengths, pattern, *,
     ROWGROUP_PAD)`` uint8 in the shared row-group layout (typically a
     zero-copy mmap view of a columnar shard), ``lengths`` the true
     payload lengths of the first ``len(lengths)`` rows (trailing rows
-    are padding). No per-payload copy, re-bucketing, or halo build —
+    are padding). No per-payload copy or re-bucketing —
     the packing cost was paid once at derive time. Returns a
     ``(live, width)`` uint8 mask, trimmed per row exactly like
     :func:`find_pattern_mask_batch` trims its outputs.
@@ -201,8 +195,9 @@ def find_pattern_mask_rowgroup(matrix, lengths, pattern, *,
     mat = np.ascontiguousarray(matrix, np.uint8)
     nrows, padded_width = mat.shape
     width = padded_width - ROWGROUP_PAD
-    if width <= 0:
-        raise ValueError("matrix must carry the ROWGROUP_PAD zero tail")
+    if width <= 0 or width % LANES:
+        raise ValueError("matrix must be lane-aligned and carry the "
+                         "ROWGROUP_PAD zero tail")
     lengths = np.asarray(lengths, np.int64)
     live = lengths.size
     if not 0 < live <= nrows:
@@ -210,51 +205,15 @@ def find_pattern_mask_rowgroup(matrix, lengths, pattern, *,
     record_dispatch("find_pattern_mask_rowgroup", width=width, rows=live,
                     padded_rows=nrows, useful_bytes=int(lengths.sum()))
     masks = pattern_scan_rowgroup(jnp.asarray(mat), jnp.asarray(pat_vec),
-                                  pat_len=plen, interpret=interpret)
+                                  pat_len=plen)
     if not trim:
         return np.asarray(masks)[:live]
     return _trim_rows(np.asarray(masks)[:live], lengths, plen)
 
 
-def find_pattern_masks_multi_rowgroup(matrix, lengths, patterns, *,
-                                      interpret: bool = True) -> np.ndarray:
-    """Per-row-pattern masks over a packed row-group — one dispatch.
-
-    ``patterns[i]`` scans row ``i``; rows from different queries share
-    the single grouped dispatch (unroll bound = longest true pattern).
-    Same layout/trim semantics as :func:`find_pattern_mask_rowgroup`.
-    """
-    lengths = np.asarray(lengths, np.int64)
-    live = lengths.size
-    if live != len(patterns):
-        raise ValueError("lengths and patterns must pair up")
-    mat = np.ascontiguousarray(matrix, np.uint8)
-    nrows, padded_width = mat.shape
-    width = padded_width - ROWGROUP_PAD
-    if width <= 0:
-        raise ValueError("matrix must carry the ROWGROUP_PAD zero tail")
-    if not 0 < live <= nrows:
-        raise ValueError(f"need 1 <= live rows <= {nrows}, got {live}")
-    pats, plens = zip(*(_check_pattern(p) for p in patterns))
-    pad_pat = np.zeros(MAX_PATTERN, np.uint8)
-    pad_pat[0] = 1  # inert: never matches an all-zero pad row
-    pat_mat = np.stack(list(pats) + [pad_pat] * (nrows - live))
-    lens = np.asarray([[n] for n in plens] + [[1]] * (nrows - live),
-                      np.int32)
-    record_dispatch("find_pattern_masks_multi_rowgroup", width=width,
-                    rows=live, padded_rows=nrows,
-                    useful_bytes=int(lengths.sum()))
-    masks = pattern_scan_rowgroup_multi(
-        jnp.asarray(mat), jnp.asarray(pat_mat), jnp.asarray(lens),
-        max_len=max(plens), interpret=interpret)
-    return _trim_rows(np.asarray(masks)[:live], lengths, np.asarray(plens))
-
-
-def find_pattern_mask(buf, pattern, *, block: int = DEFAULT_BLOCK,
-                      interpret: bool = True):
+def find_pattern_mask(buf, pattern, *, block: int = DEFAULT_BLOCK):
     """uint8 match mask (same length as ``buf``)."""
-    return find_pattern_mask_batch([buf], pattern, block=block,
-                                   interpret=interpret)[0]
+    return find_pattern_mask_batch([buf], pattern, block=block)[0]
 
 
 def find_pattern_positions(buf, pattern, **kw) -> np.ndarray:

@@ -1,4 +1,4 @@
-"""Pallas kernel: multi-byte pattern scan over uint8 buffers.
+"""Pallas kernel: multi-byte pattern scan over packed uint8 row-groups.
 
 TPU adaptation of FastWARC's SIMD bulk scanning (DESIGN.md §4): the VPU is
 an (8, 128) vector unit, so a byte-compare sweep maps onto it directly.
@@ -6,22 +6,24 @@ For a pattern ``p`` of length P, the match mask is
 
     mask[i] = AND_{j<P} (buf[i+j] == p[j])
 
-computed as P shifted uint8 compares over a VMEM-resident tile — no
-per-byte control flow, which is the whole point: the host parser's
-per-record work becomes a handful of wide vector ops.
+computed as P shifted compares over a VMEM-resident tile — no per-byte
+control flow: the host parser's per-record work becomes a handful of wide
+vector ops.
 
-Blocking: the input is tiled with real blocked ``BlockSpec``s — grid step
-``(b, j)`` maps only its ``(1, block)`` tile into VMEM, never the whole
-buffer. Match windows crossing a tile's right edge need the next
-``P − 1`` bytes; Pallas cannot express overlapping BlockSpecs, so the
-wrapper passes an explicit **halo input**: a ``(B, nblocks·MAX_PATTERN)``
-matrix whose ``(1, MAX_PATTERN)`` tile for step ``(b, j)`` holds the
-bytes just past tile ``j``'s edge. The kernel concatenates tile + halo
-and does P shifted compares, all static.
+Layout: the input is a ``(B, W + ROWGROUP_PAD)`` uint8 matrix in the
+shared row-group layout (:mod:`repro.kernels.bucketing`): payload bytes
+left-justified, zero tail. Grid step ``(i, j)`` maps the ``(rows, cols)``
+tile at row ``i·rows``, column ``j·cols``, and — through a second
+``BlockSpec`` over the *same* array — the 128 columns just past the
+tile: the halo that match windows crossing the tile's right edge read.
+The last tile's halo is the zero tail, where windows compare against
+zeros and lose (all-zero patterns are rejected by the wrappers).
 
-The 2D ``(B, nblocks)`` grid batches many record payloads into one
-``pallas_call`` (``find_pattern_mask_batch``): amortized dispatch is how
-a shard's worth of delimiter scans becomes a single kernel launch.
+Bytes are widened to int32 before comparing: v5e's VPU has no 8-bit
+vector compare. A single pattern arrives as ``MAX_PATTERN`` int32
+scalars in SMEM (scalar prefetch); per-row patterns (cross-request
+batching) arrive as a ``(B, MAX_PATTERN)`` int32 VMEM block, with ``-1``
+at the positions past a row's pattern length.
 """
 from __future__ import annotations
 
@@ -30,218 +32,119 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.bucketing import ROWGROUP_PAD
+from repro import kernels
+from repro.kernels.bucketing import LANES, ROWGROUP_PAD, grid_tiles
 
-LANES = 128
-DEFAULT_BLOCK = 64 * 1024  # 64 KiB tile + halo + mask comfortably < VMEM
+DEFAULT_BLOCK = 64 * 1024  # width-bucket granularity of the ragged-batch path
 MAX_PATTERN = 16
-GROUP_BYTES = 1 << 21  # payload bytes per row-group grid step (VMEM budget)
-MAX_GROUP = 256
+# Payload bytes per grid step. Mosaic keeps a widened int32 copy of the
+# tile live for every shifted compare (~5.5 B of scoped VMEM per payload
+# byte each, against v5e's 16 MiB limit), so the step shrinks as the
+# pattern grows: 64 KiB at 16 bytes, 256 KiB at 4 or fewer.
+STEP_BYTES = 1 << 18
+SHIFTED_STEP_BYTES = 1 << 20
 
 
-def scan_group_rows(width: int, nrows: int) -> int:
-    """Rows per grid step for the row-group scan kernels: the largest
-    divisor of ``nrows`` within the VMEM budget. Row counts are half-step
-    quantized (m·2^k, m ∈ {1, 3}) by packers, so divisors are dense."""
-    g = max(1, min(MAX_GROUP, GROUP_BYTES // max(width, 1)))
-    g = min(g, nrows)
-    while nrows % g:
-        g -= 1
-    return g
+def _widened(main_ref, halo_ref) -> jax.Array:
+    """The step's tile plus its right halo, as int32."""
+    return jnp.concatenate([main_ref[...].astype(jnp.int32),
+                            halo_ref[...].astype(jnp.int32)], axis=1)
 
 
-def _scan_kernel(buf_ref, halo_ref, pat_ref, mask_ref, *,
-                 block: int, pat_len: int):
-    """One grid step: compare one (1, block) tile against the pattern."""
-    # tile plus its right halo: every window starting in the tile is in-bounds
-    ext = jnp.concatenate([buf_ref[0, :], halo_ref[0, :]])
-    # P shifted static slices — each a wide VPU compare, no per-byte control flow
-    acc = ext[0:block] == pat_ref[0]
+def _scan_kernel(pat_ref, main_ref, halo_ref, mask_ref, *, cols: int,
+                 pat_len: int):
+    """One grid step: compare a (rows, cols) tile against one pattern."""
+    ext = _widened(main_ref, halo_ref)
+    acc = ext[:, :cols] == pat_ref[0]
     for j in range(1, pat_len):  # unrolled: P is static
-        acc = jnp.logical_and(acc, ext[j:j + block] == pat_ref[j])
-    mask_ref[0, :] = acc.astype(jnp.uint8)
+        acc = jnp.logical_and(acc, ext[:, j:j + cols] == pat_ref[j])
+    mask_ref[...] = acc.astype(jnp.int32).astype(jnp.uint8)
 
 
-def _scan_kernel_multi(buf_ref, halo_ref, pat_ref, len_ref, mask_ref, *,
-                       block: int, max_len: int):
-    """One grid step with a *per-row* pattern (cross-request batching).
-
-    ``pat_ref`` holds this row's padded pattern and ``len_ref`` its true
-    length; compare positions past the length are forced to match, so
-    rows carrying different-length patterns coexist in one dispatch.
-    """
-    ext = jnp.concatenate([buf_ref[0, :], halo_ref[0, :]])
-    plen = len_ref[0, 0]
-    acc = ext[0:block] == pat_ref[0, 0]
+def _scan_kernel_multi(pat_ref, main_ref, halo_ref, mask_ref, *, cols: int,
+                       max_len: int):
+    """One grid step with a *per-row* pattern (cross-request batching):
+    pattern positions holding -1 (past the row's length) always match."""
+    ext = _widened(main_ref, halo_ref)
+    acc = ext[:, :cols] == pat_ref[:, 0:1]
     for j in range(1, max_len):  # unrolled: max_len is static per dispatch
-        hit = ext[j:j + block] == pat_ref[0, j]
-        acc = jnp.logical_and(acc, jnp.logical_or(hit, j >= plen))
-    mask_ref[0, :] = acc.astype(jnp.uint8)
+        pj = pat_ref[:, j:j + 1]
+        acc = jnp.logical_and(
+            acc, jnp.logical_or(ext[:, j:j + cols] == pj, pj < 0))
+    mask_ref[...] = acc.astype(jnp.int32).astype(jnp.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("max_len", "block", "interpret"))
-def pattern_scan_batch_multi(padded_bufs: jax.Array, halos: jax.Array,
-                             pattern_mat: jax.Array, pat_lens: jax.Array, *,
-                             max_len: int, block: int = DEFAULT_BLOCK,
-                             interpret: bool = True) -> jax.Array:
+def _geometry(matrix: jax.Array, pat_len: int
+              ) -> tuple[int, int, int, int]:
+    nrows, padded_width = matrix.shape
+    width = padded_width - ROWGROUP_PAD
+    assert width > 0 and width % LANES == 0, \
+        "matrix must be lane-aligned and carry the ROWGROUP_PAD zero tail"
+    assert 0 < pat_len <= MAX_PATTERN
+    budget = min(STEP_BYTES, SHIFTED_STEP_BYTES // pat_len)
+    rows, cols = grid_tiles(nrows, width, unit=LANES, budget=budget)
+    return nrows, width, rows, cols
+
+
+@functools.partial(jax.jit, static_argnames=("pat_len",))
+def pattern_scan_rowgroup(matrix: jax.Array, pattern_vec: jax.Array, *,
+                          pat_len: int) -> jax.Array:
+    """Match mask over a packed row-group matrix — one dispatch.
+
+    ``matrix`` is ``(B, W + ROWGROUP_PAD)`` uint8 in the row-group
+    layout; ``pattern_vec`` is ``(MAX_PATTERN,)`` int32 holding the
+    pattern's bytes first. Returns a ``(B, W)`` uint8 mask (positions
+    past each row's true length must be trimmed by the caller).
+    """
+    nrows, width, rows, cols = _geometry(matrix, pat_len)
+    halo = cols // LANES
+    kernel = functools.partial(_scan_kernel, cols=cols, pat_len=pat_len)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nrows // rows, width // cols),
+            in_specs=[
+                pl.BlockSpec((rows, cols), lambda i, j, p: (i, j)),
+                pl.BlockSpec((rows, LANES),
+                             lambda i, j, p: (i, (j + 1) * halo)),
+            ],
+            out_specs=pl.BlockSpec((rows, cols), lambda i, j, p: (i, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nrows, width), jnp.uint8),
+        interpret=kernels.interpret_mode(),
+    )(pattern_vec, matrix, matrix)
+
+
+@functools.partial(jax.jit, static_argnames=("max_len",))
+def pattern_scan_batch_multi(matrix: jax.Array, pattern_mat: jax.Array, *,
+                             max_len: int) -> jax.Array:
     """Per-row-pattern match masks — **one** dispatch for a mixed batch.
 
     The cross-request primitive behind ``repro.serve.archive``: rows
     belonging to *different* queries (different patterns, same width
     bucket) share a single ``pallas_call``. ``pattern_mat`` is
-    ``(B, MAX_PATTERN)`` uint8 (zero-padded), ``pat_lens`` is ``(B, 1)``
-    int32; ``max_len`` bounds the static compare unroll (the longest
-    true pattern in the batch). Everything else matches
-    :func:`pattern_scan_batch`.
+    ``(B, MAX_PATTERN)`` int32, each row's pattern bytes followed by
+    ``-1``; ``max_len`` bounds the static compare unroll (the longest
+    true pattern in the batch). Layout and result as
+    :func:`pattern_scan_rowgroup`.
     """
-    nrows, width = padded_bufs.shape
-    assert width % block == 0, "wrapper must pad to a block multiple"
-    nblocks = width // block
-    assert halos.shape == (nrows, nblocks * MAX_PATTERN)
+    nrows, width, rows, cols = _geometry(matrix, max_len)
     assert pattern_mat.shape == (nrows, MAX_PATTERN)
-    assert pat_lens.shape == (nrows, 1)
-    kernel = functools.partial(_scan_kernel_multi, block=block,
+    halo = cols // LANES
+    kernel = functools.partial(_scan_kernel_multi, cols=cols,
                                max_len=max_len)
     return pl.pallas_call(
         kernel,
-        grid=(nrows, nblocks),
+        grid=(nrows // rows, width // cols),
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, j: (b, j)),
-            pl.BlockSpec((1, MAX_PATTERN), lambda b, j: (b, j)),
-            pl.BlockSpec((1, MAX_PATTERN), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0)),
+            pl.BlockSpec((rows, MAX_PATTERN), lambda i, j: (i, 0)),
+            pl.BlockSpec((rows, cols), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, LANES), lambda i, j: (i, (j + 1) * halo)),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda b, j: (b, j)),
+        out_specs=pl.BlockSpec((rows, cols), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nrows, width), jnp.uint8),
-        interpret=interpret,
-    )(padded_bufs, halos, pattern_mat, pat_lens)
-
-
-@functools.partial(jax.jit, static_argnames=("pat_len", "block", "interpret"))
-def pattern_scan_batch(padded_bufs: jax.Array, halos: jax.Array,
-                       pattern_vec: jax.Array, *, pat_len: int,
-                       block: int = DEFAULT_BLOCK,
-                       interpret: bool = True) -> jax.Array:
-    """Match mask over a padded byte matrix (one dispatch for the batch).
-
-    ``padded_bufs`` is ``(B, W)`` uint8 with ``W % block == 0``; ``halos``
-    is ``(B, (W // block) · MAX_PATTERN)`` holding each tile's right-edge
-    spillover (built by :mod:`.ops`). Returns a ``(B, W)`` uint8 mask.
-    """
-    nrows, width = padded_bufs.shape
-    assert width % block == 0, "wrapper must pad to a block multiple"
-    nblocks = width // block
-    assert halos.shape == (nrows, nblocks * MAX_PATTERN)
-    kernel = functools.partial(_scan_kernel, block=block, pat_len=pat_len)
-    return pl.pallas_call(
-        kernel,
-        grid=(nrows, nblocks),
-        in_specs=[
-            # blocked specs: each step maps only its tile (+halo), never
-            # the full buffer
-            pl.BlockSpec((1, block), lambda b, j: (b, j)),
-            pl.BlockSpec((1, MAX_PATTERN), lambda b, j: (b, j)),
-            pl.BlockSpec(pattern_vec.shape, lambda b, j: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda b, j: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((nrows, width), jnp.uint8),
-        interpret=interpret,
-    )(padded_bufs, halos, pattern_vec)
-
-
-def _scan_kernel_group(buf_ref, pat_ref, mask_ref, *, width: int,
-                       pat_len: int):
-    """One grid step: compare a (G, width + ROWGROUP_PAD) row group.
-
-    The zero right-pad (≥ MAX_PATTERN) replaces the halo input of the
-    blocked kernel: every window starting inside a row is in-bounds in
-    the tile, spilled windows compare against zeros and lose (packers
-    reject all-zero patterns). P shifted compares over the whole group —
-    one grid step per G rows instead of per (row, block), which is what
-    makes full-corpus columnar scans cheap: per-step dispatch overhead
-    is amortized over megabytes, not one 64 KiB tile.
-    """
-    ext = buf_ref[:, :]
-    acc = ext[:, 0:width] == pat_ref[0]
-    for j in range(1, pat_len):  # unrolled: P is static
-        acc = jnp.logical_and(acc, ext[:, j:j + width] == pat_ref[j])
-    mask_ref[:, :] = acc.astype(jnp.uint8)
-
-
-def _scan_kernel_group_multi(buf_ref, pat_ref, len_ref, mask_ref, *,
-                             width: int, max_len: int):
-    """Row-group step with a per-row pattern (mixed-query batching)."""
-    ext = buf_ref[:, :]
-    plen = len_ref[:, :]                       # (G, 1) broadcasts over width
-    acc = ext[:, 0:width] == pat_ref[:, 0:1]
-    for j in range(1, max_len):  # unrolled: max_len is static per dispatch
-        hit = ext[:, j:j + width] == pat_ref[:, j:j + 1]
-        acc = jnp.logical_and(acc, jnp.logical_or(hit, j >= plen))
-    mask_ref[:, :] = acc.astype(jnp.uint8)
-
-
-@functools.partial(jax.jit, static_argnames=("pat_len", "interpret"))
-def pattern_scan_rowgroup(matrix: jax.Array, pattern_vec: jax.Array, *,
-                          pat_len: int, interpret: bool = True) -> jax.Array:
-    """Match mask over a packed row-group matrix — grouped-rows grid.
-
-    ``matrix`` is ``(B, width + ROWGROUP_PAD)`` uint8 in the shared
-    row-group layout (:mod:`repro.kernels.bucketing`): payload bytes
-    left-justified, zero tail. No halo input — the zero tail bounds
-    every window. Returns a ``(B, width)`` uint8 mask (positions past
-    each row's true length must be trimmed by the caller).
-    """
-    nrows, padded_width = matrix.shape
-    width = padded_width - ROWGROUP_PAD
-    assert width > 0, "matrix must carry the ROWGROUP_PAD zero tail"
-    assert 0 < pat_len <= MAX_PATTERN
-    group = scan_group_rows(width, nrows)
-    kernel = functools.partial(_scan_kernel_group, width=width,
-                               pat_len=pat_len)
-    return pl.pallas_call(
-        kernel,
-        grid=(nrows // group,),
-        in_specs=[
-            pl.BlockSpec((group, padded_width), lambda g: (g, 0)),
-            pl.BlockSpec(pattern_vec.shape, lambda g: (0,)),
-        ],
-        out_specs=pl.BlockSpec((group, width), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((nrows, width), jnp.uint8),
-        interpret=interpret,
-    )(matrix, pattern_vec)
-
-
-@functools.partial(jax.jit, static_argnames=("max_len", "interpret"))
-def pattern_scan_rowgroup_multi(matrix: jax.Array, pattern_mat: jax.Array,
-                                pat_lens: jax.Array, *, max_len: int,
-                                interpret: bool = True) -> jax.Array:
-    """Per-row-pattern match masks over a packed row-group matrix.
-
-    The columnar twin of :func:`pattern_scan_batch_multi`: rows carrying
-    different patterns (different queries) share one grouped dispatch.
-    ``pattern_mat`` is ``(B, MAX_PATTERN)`` uint8 zero-padded,
-    ``pat_lens`` ``(B, 1)`` int32; compare positions past a row's true
-    pattern length are forced to match.
-    """
-    nrows, padded_width = matrix.shape
-    width = padded_width - ROWGROUP_PAD
-    assert width > 0, "matrix must carry the ROWGROUP_PAD zero tail"
-    assert pattern_mat.shape == (nrows, MAX_PATTERN)
-    assert pat_lens.shape == (nrows, 1)
-    assert 0 < max_len <= MAX_PATTERN
-    group = scan_group_rows(width, nrows)
-    kernel = functools.partial(_scan_kernel_group_multi, width=width,
-                               max_len=max_len)
-    return pl.pallas_call(
-        kernel,
-        grid=(nrows // group,),
-        in_specs=[
-            pl.BlockSpec((group, padded_width), lambda g: (g, 0)),
-            pl.BlockSpec((group, MAX_PATTERN), lambda g: (g, 0)),
-            pl.BlockSpec((group, 1), lambda g: (g, 0)),
-        ],
-        out_specs=pl.BlockSpec((group, width), lambda g: (g, 0)),
-        out_shape=jax.ShapeDtypeStruct((nrows, width), jnp.uint8),
-        interpret=interpret,
-    )(matrix, pattern_mat, pat_lens)
+        interpret=kernels.interpret_mode(),
+    )(pattern_mat, matrix, matrix)

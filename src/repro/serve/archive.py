@@ -146,8 +146,7 @@ class ArchiveGateway:
                  engine_factory=None,
                  max_pending: int = 256, max_batch_requests: int = 16,
                  cache_bytes: int = 64 << 20, cache_admission: str = "tinylfu",
-                 use_kernel: bool = True,
-                 interpret: bool = True, poll_interval_s: float = 0.02,
+                 use_kernel: bool = True, poll_interval_s: float = 0.02,
                  default_deadline_s: float | None = None,
                  trace_requests: bool = True,
                  flight_recorder: obs_flight.FlightRecorder | None = None,
@@ -173,8 +172,7 @@ class ArchiveGateway:
         self._reap_lock = threading.Lock()
 
         def _default_engine(_i: int) -> QueryEngine:
-            return QueryEngine(index, use_kernel=use_kernel,
-                               interpret=interpret)
+            return QueryEngine(index, use_kernel=use_kernel)
 
         factory = engine_factory if engine_factory is not None \
             else _default_engine

@@ -774,8 +774,7 @@ class ShardScheduler:
                                 "shard": self.shard_id}) as sp:
             with _DISPATCH_LOCK:  # shards share one device (module note)
                 masks = find_pattern_masks_multi(
-                    chunk_bufs, chunk_pats, block=self.engine.scan_block,
-                    interpret=self.engine.interpret)
+                    chunk_bufs, chunk_pats, block=self.engine.scan_block)
             dispatches = dispatch_count(
                 [len(b) for b in chunk_bufs], self.engine.scan_block)
             if sp is not None:

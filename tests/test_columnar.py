@@ -4,17 +4,16 @@ column-scan query path vs the CDX+seek engine (byte-identical hits),
 the mmap borrow rule, and the CDX v1 → v2 → columnar migration chain.
 
 Tier-2 selection: ``pytest -m columnar`` (marker registered in
-pytest.ini); the whole module also runs under the tier-1 suite. The
-real-zstandard cases (frame walker on frames an actual compressor
-produced, zstd-corpus derive) skip where zstandard is absent — CI
-installs it.
+pytest.ini); the whole module also runs under the tier-1 suite.
 """
 import os
 import struct
 
 import numpy as np
 import pytest
+import zstandard
 
+from repro import obs
 from repro.core.warc import FastWARCIterator, WarcRecordType
 from repro.columnar import (
     ColumnFile,
@@ -34,16 +33,11 @@ from repro.index import (
 )
 from repro.kernels.bucketing import ROWGROUP_PAD, payload_width, \
     quantize_count
-
-try:
-    import zstandard  # noqa: F401
-    _HAVE_ZSTD = True
-except ImportError:
-    _HAVE_ZSTD = False
+from repro.kernels.digest_sig.digest_sig import MAX_NGRAM
 
 pytestmark = pytest.mark.columnar
 
-_COMPRESSIONS = ["none", "gzip", "lz4"] + (["zstd"] if _HAVE_ZSTD else [])
+_COMPRESSIONS = ["none", "gzip", "lz4", "zstd"]
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +185,26 @@ def test_derive_columns_match_cdx_build(corpus):
     for i in range(len(store)):
         assert store.uri(i) == index.uri(i)
         assert store.mime(i) == index.mime(i)
+
+
+@pytest.mark.parametrize("ngram", [MAX_NGRAM, MAX_NGRAM + 1])
+def test_derive_ngram_limit(corpus, tmp_path, ngram):
+    """derive sweeps n-grams up to MAX_NGRAM with the fused kernel and
+    longer ones on the host; both equal the host CDX build."""
+    paths, _, _ = corpus
+
+    def swept() -> int:
+        return int(obs.registry().snapshot().counters.get(
+            "kernel.digest_signature_rowgroup.dispatches", 0))
+
+    before = swept()
+    store = derive(paths, str(tmp_path / f"n{ngram}.repcol"),
+                   sig_ngram=ngram)
+    assert (swept() > before) == (ngram <= MAX_NGRAM)
+    host = build_index(paths, sig_ngram=ngram, fused=False)
+    assert np.array_equal(store.digest, host.digest)
+    assert np.array_equal(store.signatures, host.signatures)
+    store.close()
 
 
 def test_derive_payloads_and_timestamps_match_source(corpus):
@@ -391,10 +405,9 @@ def test_cdx_v1_to_v2_to_columnar_migration(tmp_path):
 
 
 # --------------------------------------------------------------------------
-# zstd frame walker on real zstandard-produced frames (CI installs it)
+# zstd frame walker on real zstandard-produced frames
 # --------------------------------------------------------------------------
 
-@pytest.mark.skipif(not _HAVE_ZSTD, reason="zstandard not installed")
 def test_walk_frames_real_multiframe_with_skippable():
     from repro.core.warc.zstd_frames import frame_table, walk_frames
 
@@ -420,7 +433,6 @@ def test_walk_frames_real_multiframe_with_skippable():
         assert got == want
 
 
-@pytest.mark.skipif(not _HAVE_ZSTD, reason="zstandard not installed")
 def test_frame_table_measures_sizeless_real_frames():
     """Streamed zstandard output omits Frame_Content_Size; the table
     falls back to decompress-to-measure for exactly those frames."""
@@ -445,7 +457,6 @@ def test_frame_table_measures_sizeless_real_frames():
     assert offs.tolist() == [f.comp_off for f in frames]
 
 
-@pytest.mark.skipif(not _HAVE_ZSTD, reason="zstandard not installed")
 def test_derive_over_zstd_corpus_payload_identity(tmp_path):
     p = str(tmp_path / "z.warc.zstd")
     write_corpus(p, CorpusSpec(n_pages=6, seed=11), "zstd")
@@ -455,9 +466,9 @@ def test_derive_over_zstd_corpus_payload_identity(tmp_path):
         assert len(store) == len(records)
         for i, rec in enumerate(records):
             assert store.payload(i) == rec.content
-        # the store's synthesized index flags zstd rows as frameless
+        # the store's synthesized index flags zstd rows as frameless (a
+        # temporary: its columns borrow the mapping close() releases)
         from repro.index.cdx import NO_FRAME
-        synth = store.as_index()
-        assert np.all(synth.frame_off == NO_FRAME)
+        assert np.all(store.as_index().frame_off == NO_FRAME)
     finally:
         store.close()

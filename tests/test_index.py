@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
 from repro.core.warc import FastWARCIterator, WarcRecordType, read_record_at
 from repro.core.warc.writer import serialize_record
 from repro.data.synth import CorpusSpec, write_corpus
@@ -29,16 +30,11 @@ from repro.index import (
     verify_index,
 )
 from repro.index.signature import candidate_mask, pattern_bits, signature_of
-
-try:
-    import zstandard  # noqa: F401
-    _HAVE_ZSTD = True
-except ImportError:
-    _HAVE_ZSTD = False
+from repro.kernels.digest_sig.digest_sig import MAX_NGRAM
 
 pytestmark = pytest.mark.index
 
-_COMPRESSIONS = ["none", "gzip", "lz4"] + (["zstd"] if _HAVE_ZSTD else [])
+_COMPRESSIONS = ["none", "gzip", "lz4", "zstd"]
 
 
 @pytest.fixture(scope="module")
@@ -387,6 +383,17 @@ def test_fused_build_nondefault_geometry(corpus):
     np.testing.assert_array_equal(fused.signatures, host.signatures)
 
 
+def test_fused_worker_build_rejected_on_accelerator(corpus, monkeypatch):
+    """One process holds the chip: a fused build must not dispatch the
+    digest kernel from pool workers there (it raises before any fork)."""
+    import jax
+
+    paths, _ = corpus
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="one process holds"):
+        build_index(paths, workers=2, fused=True)
+
+
 def test_non_power_of_two_bits_fall_back_to_host(corpus):
     paths, _ = corpus
     # 192 = 3·64: a legal CDX geometry the kernel cannot cover — the
@@ -394,6 +401,25 @@ def test_non_power_of_two_bits_fall_back_to_host(corpus):
     idx = build_index(paths, sig_bits=192, fused=True)
     ref = build_index(paths, sig_bits=192, fused=False)
     np.testing.assert_array_equal(idx.signatures, ref.signatures)
+
+
+@pytest.mark.parametrize("ngram", [MAX_NGRAM, MAX_NGRAM + 1])
+def test_fused_build_ngram_limit(corpus, ngram):
+    """n-grams up to MAX_NGRAM take the fused kernel; longer ones take the
+    host two-pass. Both builds, and verify_index, agree with the host."""
+    paths, _ = corpus
+
+    def swept() -> int:
+        return int(obs.registry().snapshot().counters.get(
+            "kernel.digest_signature_batch.dispatches", 0))
+
+    before = swept()
+    fused = build_index(paths, sig_ngram=ngram, fused=True)
+    assert (swept() > before) == (ngram <= MAX_NGRAM)
+    host = build_index(paths, sig_ngram=ngram, fused=False)
+    np.testing.assert_array_equal(fused.digest, host.digest)
+    np.testing.assert_array_equal(fused.signatures, host.signatures)
+    assert verify_index(fused, limit=6, check_signatures=True) == [True] * 6
 
 
 def test_verify_index_checks_signatures(corpus):
@@ -591,7 +617,6 @@ def test_zstd_frame_walker_rejects_garbage():
         walk_frames(_raw_zstd_frame(b"A" * 40)[:-3])
 
 
-@pytest.mark.skipif(not _HAVE_ZSTD, reason="zstandard not installed")
 def test_zstd_frame_hints_seek_without_full_decompress(tmp_path):
     """v2 CDX stores the containing frame per zstd record; a hinted read
     must parse the record without inflating the whole shard."""
@@ -610,7 +635,6 @@ def test_zstd_frame_hints_seek_without_full_decompress(tmp_path):
             assert reader._zbuf is None
 
 
-@pytest.mark.skipif(not _HAVE_ZSTD, reason="zstandard not installed")
 def test_zstd_v1_index_compat_falls_back(tmp_path):
     """A CDX saved before the frame columns existed (v1) must load and
     serve zstd shards through the legacy full-decompress path."""

@@ -26,6 +26,54 @@ from repro.kernels.pattern_scan.ref import pattern_mask_ref
 
 
 # --------------------------------------------------------------------------
+# interpret mode and the compile cache
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None), ("rocm", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    import repro.kernels
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=backend):
+            repro.kernels.interpret_mode()
+    else:
+        assert repro.kernels.interpret_mode() is interpret
+
+
+def test_compile_cache_directory(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR places the persistent cache; unset, it is
+    the checkout's fixed .jax_cache. Compiled programs land there whether
+    JAX was imported before the call (config) or after it (environment)."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    import repro.kernels
+
+    compile_ = ("jax.jit(lambda x: x * 3 + 1)(jax.numpy.arange(5.0))"
+                ".block_until_ready()")
+    jax_first = ("import jax, repro.kernels as k; "
+                 f"print(k.enable_compile_cache()); {compile_}")
+    jax_after = ("import repro.kernels as k; p = k.enable_compile_cache(); "
+                 f"import jax; {compile_}; print(p)")
+    root = Path(repro.kernels.__file__).resolve().parents[3]
+    env = {**os.environ, "PYTHONPATH": str(root / "src")}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    for i, script in enumerate((jax_first, jax_after)):
+        where = tmp_path / f"jc{i}"
+        for extra, expect in (({"JAX_COMPILATION_CACHE_DIR": str(where)},
+                               where), ({}, root / ".jax_cache")):
+            out = subprocess.run(
+                [sys.executable, "-c", script], env={**env, **extra},
+                check=True, capture_output=True, text=True, timeout=120)
+            assert out.stdout.split()[-1] == str(expect)
+            assert any(expect.iterdir())
+
+
+# --------------------------------------------------------------------------
 # pattern_scan
 # --------------------------------------------------------------------------
 
@@ -240,6 +288,54 @@ def test_pattern_scan_batch_cross_tile_matches():
     masks = find_pattern_mask_batch([bytes(buf)], b"ABCD", block=block)
     assert sorted(np.flatnonzero(masks[0]).tolist()) == [
         block - 3, 2 * block - 2, 3 * block - 1]
+
+
+@pytest.mark.parametrize("nrows,width", [(1, 256), (12, 768), (24, 6144),
+                                         (1024, 256), (6, 1 << 20)])
+def test_grid_tiles_are_legal_tpu_blocks(nrows, width):
+    """Every tile is a legal Mosaic block (rows a multiple of 8 or the
+    whole array, cols a lane multiple dividing the width) inside the
+    byte budget wherever the shape allows."""
+    from repro.kernels.bucketing import LANES, SUBLANES, grid_tiles
+
+    budget = 1 << 16
+    rows, cols = grid_tiles(nrows, width, unit=LANES, budget=budget)
+    assert nrows % rows == 0 and (rows % SUBLANES == 0 or rows == nrows)
+    assert width % cols == 0 and cols % LANES == 0
+    padded_rows = -(-rows // SUBLANES) * SUBLANES
+    assert padded_rows * cols <= budget or cols == LANES or \
+        (rows == nrows and nrows % SUBLANES)
+
+
+def test_rowgroup_scan_and_digest_match_ragged_batch():
+    """The packed row-group entry points (a 3·2^k-row group several grid
+    steps wide) agree with the ragged-batch wrappers."""
+    from repro.kernels.bucketing import ROWGROUP_PAD
+    from repro.kernels.digest_sig import digest_signature_rowgroup
+    from repro.kernels.pattern_scan import find_pattern_mask_rowgroup
+
+    rng = np.random.default_rng(21)
+    width = 8192
+    sizes = rng.integers(1, width, 22)
+    payloads = [rng.integers(0, 4, int(n), np.uint8).tobytes()
+                for n in sizes]
+    mat = np.zeros((24, width + ROWGROUP_PAD), np.uint8)
+    for i, p in enumerate(payloads):
+        mat[i, :len(p)] = np.frombuffer(p, np.uint8)
+    lengths = np.asarray(sizes, np.int64)
+    pattern = b"\x01\x02\x03"
+    trimmed = find_pattern_mask_rowgroup(mat, lengths, pattern)
+    raw = find_pattern_mask_rowgroup(mat, lengths, pattern, trim=False)
+    for i, mask in enumerate(find_pattern_mask_batch(payloads, pattern,
+                                                     block=1024)):
+        np.testing.assert_array_equal(trimmed[i, :len(mask)], mask)
+        assert not trimmed[i, len(mask):].any()
+        valid = max(len(mask) - len(pattern) + 1, 0)  # windows in bounds
+        np.testing.assert_array_equal(raw[i, :valid], mask[:valid])
+    d, s = digest_signature_rowgroup(mat, lengths)
+    dr, sr = digest_signature_reference(payloads)
+    np.testing.assert_array_equal(d, dr)
+    np.testing.assert_array_equal(s, sr)
 
 
 # --------------------------------------------------------------------------

@@ -21,11 +21,7 @@ from repro.core.warc import (
     serialize_record,
 )
 
-try:
-    import zstandard  # noqa: F401
-    _CODECS = ["none", "gzip", "lz4", "zstd"]
-except ImportError:  # optional codec; container images vary
-    _CODECS = ["none", "gzip", "lz4"]
+_CODECS = ["none", "gzip", "lz4", "zstd"]
 
 _hdr_name = st.text(
     alphabet=st.characters(min_codepoint=0x41, max_codepoint=0x5A),

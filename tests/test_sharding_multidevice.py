@@ -62,14 +62,14 @@ _SCRIPT = textwrap.dedent("""
 
     # -- compressed psum over an axis (shard_map) ------------------------
     from repro.train.grad_compress import compressed_psum
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh1d = jax.make_mesh((8,), ("pod",))
     xs = jnp.arange(8.0 * 4).reshape(8, 4) / 7.0
-    f = shard_map(lambda x: compressed_psum(x[0], "pod")[None],
-                  mesh=mesh1d, in_specs=P("pod", None),
-                  out_specs=P("pod", None))
-    got = f(xs)
-    expect = xs.sum(0)
+    f = jax.jit(shard_map(lambda x: compressed_psum(x[0], "pod")[None],
+                          mesh=mesh1d, in_specs=P("pod", None),
+                          out_specs=P("pod", None)))
+    got = np.asarray(f(xs))
+    expect = np.asarray(xs).sum(0)
     err = float(jnp.abs(got[0] - expect).max())
     results["compressed_psum_err"] = err
 

@@ -20,16 +20,6 @@ from repro.core.warc.streams import GZipStream, LZ4Stream
 from repro.core.warc.xxh32 import xxh32
 from repro.data.synth import CorpusSpec, generate_warc, records_in
 
-try:
-    import zstandard  # noqa: F401
-    _HAS_ZSTD = True
-except ImportError:  # optional codec; container images vary
-    _HAS_ZSTD = False
-
-_ZSTD_PARAM = pytest.param(
-    "zstd", marks=pytest.mark.skipif(not _HAS_ZSTD,
-                                     reason="zstandard not installed"))
-
 
 # --------------------------------------------------------------------------
 # xxh32 / LZ4 codec
@@ -184,7 +174,7 @@ def test_record_lazy_headers_and_fields():
     assert rec.http_payload == b"body"
 
 
-@pytest.mark.parametrize("compression", ["none", "gzip", "lz4", _ZSTD_PARAM])
+@pytest.mark.parametrize("compression", ["none", "gzip", "lz4", "zstd"])
 def test_iterator_all_compressions(compression):
     spec = CorpusSpec(n_pages=40, seed=7)
     data = generate_warc(spec, compression)
@@ -199,7 +189,7 @@ def test_iterator_all_compressions(compression):
         assert r.http_payload.startswith(b"<!doctype html>")
 
 
-@pytest.mark.parametrize("compression", ["none", "gzip", "lz4", _ZSTD_PARAM])
+@pytest.mark.parametrize("compression", ["none", "gzip", "lz4", "zstd"])
 def test_record_type_filtering_and_skip_count(compression):
     spec = CorpusSpec(n_pages=25, seed=3)
     data = generate_warc(spec, compression)
@@ -281,7 +271,7 @@ def test_digest_roundtrip():
 
 
 def test_writer_roundtrip_all_compressions(tmp_path):
-    compressions = ["none", "gzip", "lz4"] + (["zstd"] if _HAS_ZSTD else [])
+    compressions = ["none", "gzip", "lz4", "zstd"]
     for compression in compressions:
         sink = io.BytesIO()
         w = WarcWriter(sink, compression)
